@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import inspect
 import math
 import signal as _signal
@@ -43,6 +44,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional, Protocol,
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.batching import Sampler
 from repro.core.gcn import GCNConfig, gcn_loss, init_gcn, micro_f1
@@ -228,16 +230,22 @@ class SingleDeviceBackend:
     def stream(self, batches):
         return batches
 
+    def _args(self, state, payload):
+        return tuple(state[k] for k in self._state_keys()) + (payload,)
+
+    def _state_keys(self):
+        # the step's positional state arguments, in order
+        return (("params", "opt", "rng", "scale") if self._policy.scaled
+                else ("params", "opt", "rng"))
+
     def step(self, state, payload):
-        if self._policy.scaled:
-            params, opt_state, rng, scale, loss, aux = self._step(
-                state["params"], state["opt"], state["rng"],
-                state["scale"], payload)
-            return {"params": params, "opt": opt_state, "rng": rng,
-                    "scale": scale}, loss, aux
-        params, opt_state, rng, loss, aux = self._step(
-            state["params"], state["opt"], state["rng"], payload)
-        return {"params": params, "opt": opt_state, "rng": rng}, loss, aux
+        *new, loss, aux = self._step(*self._args(state, payload))
+        return dict(zip(self._state_keys(), new)), loss, aux
+
+    def lower(self, state, payload):
+        """AOT-lower the step `step(state, payload)` would run (compile
+        time and the compiled program, without running it)."""
+        return self._step.__wrapped__.lower(*self._args(state, payload))
 
     def params(self, state):
         return state["params"]
@@ -259,7 +267,11 @@ class ShardMapBackend:
                                       make_gcn_train_step)
         self.opt = opt
         self.compression = compression
+        self.mesh, self.dp_axis = mesh, dp_axis
         self.dsize = int(mesh.shape[dp_axis])
+        # where a stacked payload lives: one batch per device of the DP
+        # axis (the Engine's prefetch transfer places it there directly)
+        self.batch_sharding = NamedSharding(mesh, PartitionSpec(dp_axis))
         self.microbatches = max(1, int(microbatches))
         # _dp_groups holds up to dsize*microbatches raw sampler payloads
         # before the stack copies them — that whole group must outlive
@@ -274,8 +286,9 @@ class ShardMapBackend:
             spmm_xw=spmm_xw)
 
     def init(self, params, rng):
-        return {"dist": self._init_state(params, self.opt, self.dsize,
-                                         self.compression,
+        return {"dist": self._init_state(params, self.opt, self.mesh,
+                                         axis_name=self.dp_axis,
+                                         compression=self.compression,
                                          policy=self._policy),
                 "rng": rng}
 
@@ -796,7 +809,11 @@ class Engine:
                 measuring = self.prefetch_auto and self._auto_depth is None
                 effective = ((self._auto_depth or 0) if self.prefetch_auto
                              else self.prefetch)
-                transfer = jax.device_put if effective > 0 else None
+                # a DP payload goes straight to its shards, not device 0
+                transfer = (functools.partial(
+                    jax.device_put,
+                    device=getattr(self.backend, "batch_sharding", None))
+                    if effective > 0 else None)
                 build_acc = [0.0]
                 step_total = 0.0
                 if measuring:

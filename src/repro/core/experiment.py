@@ -662,7 +662,8 @@ def build_mesh(spec: ExperimentSpec):
             f"visible; set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={n} (before jax initializes) or lower "
             f"data_shards")
-    return jax.make_mesh((n,), (spec.execution.dp_axis,))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((n,), (spec.execution.dp_axis,))
 
 
 def build_hooks(spec: ExperimentSpec, graph: CSRGraph, cfg: GCNConfig,

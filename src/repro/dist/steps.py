@@ -24,7 +24,7 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.gcn import GCNConfig, gcn_loss
 from repro.core.precision import (all_finite, init_scale_state,
@@ -180,18 +180,35 @@ def make_encode_step(cfg: ArchConfig, policy: CellPolicy,
 # ----------------------------------------------------------------------
 # GCN data-parallel step (shard_map over cluster batches)
 # ----------------------------------------------------------------------
-def init_gcn_train_state(params: PyTree, opt: Optimizer, nshards: int,
-                         compression=None, policy=None) -> Dict:
+def gcn_state_shardings(mesh, axis_name: str, compression=None,
+                        policy=None) -> Dict[str, NamedSharding]:
+    """Placement of each DP train-state entry: everything replicated
+    except the error-feedback residuals, one row per shard."""
+    rep = NamedSharding(mesh, P())
+    out = {"params": rep, "opt": rep}
+    if isinstance(compression, int):
+        out["err"] = NamedSharding(mesh, P(axis_name))
+    if policy is not None and policy.scaled:
+        out["scale"] = rep
+    return out
+
+
+def init_gcn_train_state(params: PyTree, opt: Optimizer, mesh, *,
+                         axis_name: str = "data", compression=None,
+                         policy=None) -> Dict:
     """{params, opt} (+ per-shard error-feedback residuals, stacked on a
     leading shard axis, when int compression is on; + replicated loss
-    "scale" state when the precision policy uses loss scaling)."""
+    "scale" state when the precision policy uses loss scaling), placed
+    on the mesh by `gcn_state_shardings`."""
+    nshards = int(mesh.shape[axis_name])
     state = {"params": params, "opt": opt.init(params)}
     if isinstance(compression, int):
         state["err"] = jax.tree_util.tree_map(
             lambda p: jnp.zeros((nshards,) + p.shape, jnp.float32), params)
     if policy is not None and policy.scaled:
         state["scale"] = init_scale_state(policy)
-    return state
+    return jax.device_put(state, gcn_state_shardings(mesh, axis_name,
+                                                     compression, policy))
 
 
 def make_gcn_train_step(cfg: GCNConfig, opt: Optimizer, mesh, *,
@@ -232,8 +249,6 @@ def make_gcn_train_step(cfg: GCNConfig, opt: Optimizer, mesh, *,
     skip-update decision — params/opt/err frozen, dynamic scale backed
     off — consistent across the mesh by construction.
     """
-    from jax.experimental.shard_map import shard_map
-
     if compression not in (None, "bf16", 4, 8):
         raise ValueError(
             f"compression must be None, 'bf16', 4 or 8; got {compression!r}")
@@ -347,17 +362,19 @@ def make_gcn_train_step(cfg: GCNConfig, opt: Optimizer, mesh, *,
                for kk, v in aux_local.items()}
         return new_state, loss, aux
 
-    state_spec = {"params": P(), "opt": P()}
-    if bits is not None:
-        state_spec["err"] = P(axis_name)
-    if pol.scaled:
-        state_spec["scale"] = P()
-
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(state_spec, P(), P(axis_name)),
-                   out_specs=(state_spec, P(), P()),
-                   check_rep=False)
+    state_sh = gcn_state_shardings(mesh, axis_name, compression, pol)
+    state_spec = {k: s.spec for k, s in state_sh.items()}
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(state_spec, P(), P(axis_name)),
+                       out_specs=(state_spec, P(), P()),
+                       check_vma=False)
+    # explicit placements, so no argument is staged on device 0 first:
+    # one stacked batch per device along the DP axis, state per
+    # gcn_state_shardings
+    rep = NamedSharding(mesh, P())
     # step.nonfinite_loss injection seam (runtime.faults): transparent
     # passthrough unless a FaultPlan is installed — the stacked batch is
     # the last argument, same as the single-device step
-    return faults.wrap_step_faults(jax.jit(fn, donate_argnums=(0,)))
+    return faults.wrap_step_faults(jax.jit(
+        fn, in_shardings=(state_sh, rep, NamedSharding(mesh, P(axis_name))),
+        out_shardings=(state_sh, rep, rep), donate_argnums=(0,)))

@@ -27,7 +27,10 @@ def spmm_block_ell_ref(blocks: jnp.ndarray, block_cols: jnp.ndarray,
         else jnp.float32
     xb = x.reshape(-1, B, F)                      # (ncb, B, F)
     gathered = xb[block_cols].reshape(nrb, K * B, F)
-    a = blocks.transpose(0, 2, 1, 3).reshape(nrb, B, K * B)
+    # jnp.transpose, not the method: outside jit a custom-VJP residual
+    # arrives as a host array type that has no .transpose
+    a = jnp.transpose(jnp.asarray(blocks), (0, 2, 1, 3)).reshape(nrb, B,
+                                                                 K * B)
     y = jax.lax.dot_general(a.astype(op_dtype),
                             gathered.astype(op_dtype),
                             (((2,), (1,)), ((0,), (0,))),

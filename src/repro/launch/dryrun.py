@@ -33,8 +33,7 @@ from repro.dist.steps import (make_decode_step, make_encode_step,
                               make_prefill_step, make_train_step,
                               spec_train_state)
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.mesh import (axis_size, data_axes, make_production_mesh,
-                               use_mesh)
+from repro.launch.mesh import axis_size, data_axes, make_production_mesh
 from repro.models.config import SHAPES
 from repro.models.lm import spec_caches, spec_params
 from repro.models.spec import shape_tree
@@ -71,7 +70,7 @@ def lower_cell(arch_name: str, shape_name: str, mesh, policy: CellPolicy):
     bsh = batch_pspec(bspecs, mesh, rules)
     act_spec = P(rules.get("batch"), None, None)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             st_specs = spec_train_state(cfg)
             st_sh = shardings_for(st_specs, mesh, rules)

@@ -37,8 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.gcn import GCNConfig, gcn_loss, init_gcn
 from repro.kernels import BlockEllAdj
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.mesh import (axis_size, data_axes, make_production_mesh,
-                               use_mesh)
+from repro.launch.mesh import axis_size, data_axes, make_production_mesh
 from repro.nn.optim import adamw, apply_updates
 
 RESULTS = pathlib.Path(__file__).resolve().parents[3] / "results" / "dryrun"
@@ -147,7 +146,7 @@ def build(variant: str, mesh):
 
 def run(variant: str, multi_pod: bool = False) -> dict:
     mesh = make_production_mesh(multi_pod=multi_pod)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, st_shapes, batch = build(variant, mesh)
         t0 = time.perf_counter()
         lowered = jitted.lower(st_shapes, batch)

@@ -20,24 +20,25 @@ HBM_BW = 819e9                  # B/s
 ICI_BW = 50e9                   # B/s per link (~4 links usable per chip)
 
 
-def use_mesh(mesh):
-    """Mesh context manager across jax versions: jax.set_mesh where it
-    exists (>= 0.5), else the Mesh object's own context manager (which
-    pjit-era jax uses to resolve PartitionSpec constraints)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def make_mesh(shape, axes):
+    """jax.make_mesh with Auto axes on every dimension. The steps place
+    their operands through in_shardings / shard_map specs and pin
+    activations with with_sharding_constraint, which under jax's default
+    Explicit axes would act as an assert; Auto axes also keep sharded
+    outputs indexable on the host."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Small host mesh for tests (requires xla_force_host_platform_device_count)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

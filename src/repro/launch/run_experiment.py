@@ -88,6 +88,8 @@ def main(argv=None) -> int:
         raise SystemExit("--resume needs run.checkpoint_dir in the spec "
                          "(e.g. --set run.checkpoint_dir=/tmp/ck)")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     exp = build_experiment(spec)
     # the reproducibility artifact goes down BEFORE training so a
     # hard-killed run can still be resumed via --spec <...>/spec.json

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_arch
 from repro.dist.sharding import CellPolicy, make_rules, shardings_for
 from repro.dist.steps import make_decode_step, make_prefill_step
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.config import ShapeConfig
 from repro.models.lm import spec_caches, spec_params
 from repro.models.spec import init_tree
@@ -47,14 +47,14 @@ def main():
     shape = ShapeConfig("cli", "decode", max_seq, args.batch)
 
     if args.mesh == "host":
-        mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(args.mesh == "multipod"))
     policy = CellPolicy(fsdp=False, remat=False)
     rules = make_rules(mesh, cfg, shape, policy)
     act_spec = P(rules.get("batch"), None, None)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_specs = spec_params(cfg)
         c_specs = spec_caches(cfg, args.batch, max_seq)
         p_sh = shardings_for(p_specs, mesh, rules)

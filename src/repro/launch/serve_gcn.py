@@ -93,6 +93,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     spec = load_spec(args)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ckpt_dir = (args.checkpoint_dir or spec.run.checkpoint_dir
                 or str(pathlib.Path(args.results_dir) / spec.name
                        / "checkpoints"))

@@ -25,8 +25,8 @@ from repro.data.tokens import Prefetcher, TokenPipeline
 from repro.dist.sharding import CellPolicy, batch_pspec, make_rules, \
     shardings_for
 from repro.dist.steps import make_train_step, spec_train_state
-from repro.launch.mesh import (axis_size, data_axes, make_production_mesh,
-                               use_mesh)
+from repro.launch.mesh import (axis_size, data_axes, make_mesh,
+                               make_production_mesh)
 from repro.models.config import ShapeConfig
 from repro.models.spec import init_tree, shape_tree, spec_params as count_p
 from repro.nn.optim import adamw, warmup_cosine_schedule
@@ -56,7 +56,7 @@ def main():
 
     if args.mesh == "host":
         ndev = len(jax.devices())
-        mesh = jax.make_mesh((ndev, 1), ("data", "model"))
+        mesh = make_mesh((ndev, 1), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(args.mesh == "multipod"))
 
@@ -75,7 +75,7 @@ def main():
     print(f"[train] {cfg.name}: {count_p(st_specs['params']):,} params, "
           f"mesh {dict(mesh.shape)}")
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step_fn, in_shardings=(st_sh, None),
                          out_shardings=(st_sh, None), donate_argnums=(0,))
         state = init_tree(st_specs, jax.random.PRNGKey(args.seed))

@@ -272,21 +272,10 @@ def moe_capacity(cfg: ArchConfig, tokens: int) -> int:
 
 
 def ambient_axes():
-    """Mesh (data, model) axes from the ambient mesh context — jax.set_mesh
-    on new jax, the pjit-era `with mesh:` resource env on 0.4.x. (None,
-    None) when tracing without a mesh — plain CPU tests. Also used by
-    repro.dist.steps to decide whether activation constraints apply."""
-    names = ()
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        names = tuple(m.axis_names) if m is not None else ()
-    except Exception:
-        try:
-            from jax._src.mesh import thread_resources
-            pm = thread_resources.env.physical_mesh
-            names = tuple(pm.axis_names) if not pm.empty else ()
-        except Exception:
-            names = ()
+    """Mesh (data, model) axes from the ambient jax.set_mesh context.
+    (None, None) when tracing without a mesh — plain CPU tests. Also used
+    by repro.dist.steps to decide whether activation constraints apply."""
+    names = tuple(jax.sharding.get_abstract_mesh().axis_names)
     data = tuple(a for a in ("pod", "data") if a in names) or None
     model = "model" if "model" in names else None
     return data, model

@@ -43,6 +43,7 @@ import jax
 import numpy as np
 
 from repro.core.gcn import GCNConfig
+from repro.core.kslots import pow2_ceil
 from repro.graph.csr import CSRGraph
 from repro.graph.normalization import normalize_csr
 from repro.kernels.ops import _resolve_spmm, block_ell_from_csr
@@ -85,15 +86,21 @@ def _pad_to(n: int, block: int) -> int:
     return -(-n // block) * block
 
 
-def _prop_rows(ip, ix, dt, rows, x_pad, block) -> np.ndarray:
+def _prop_rows(ip, ix, dt, rows, x_pad, block, nr_pad) -> np.ndarray:
     """y = Â[rows, :] @ x for one cluster block: CSR row slice →
     block-ELL tiles → forward spmm. `x_pad` is the (padded-N, F) dense
-    operand shared across clusters within a layer."""
+    operand shared across clusters within a layer (already on the
+    device). Every cluster's tiles are padded to `nr_pad` rows and a
+    pow2 slot count (zero tiles, exact), so the kernel compiles once per
+    slot bucket and width instead of once per cluster."""
     sip, six, sdt = _slice_rows(ip, ix, dt, rows)
-    nr_pad = _pad_to(len(rows), block)
     blocks, cols = block_ell_from_csr(sip, six, sdt,
                                       n_cols=x_pad.shape[0],
                                       block=block, n_rows=nr_pad)
+    k = blocks.shape[1]
+    pad = ((0, 0), (0, pow2_ceil(k) - k))
+    blocks = np.pad(blocks, pad + ((0, 0), (0, 0)))
+    cols = np.pad(cols, pad)
     return _forward_spmm(blocks, cols, x_pad)[:len(rows)]
 
 
@@ -127,14 +134,17 @@ def full_graph_embeddings(params, graph: CSRGraph, parts: np.ndarray,
     layers = jax.tree_util.tree_map(np.asarray, params["layers"])
     num_parts = int(np.asarray(parts).max()) + 1
     clusters = [np.where(parts == c)[0] for c in range(num_parts)]
+    nr_pad = _pad_to(max(len(rows) for rows in clusters), block)
 
     def propagate(x):
         x_pad = np.zeros((n_pad, x.shape[1]), np.float32)
         x_pad[:n] = x
+        x_pad = jax.numpy.asarray(x_pad)    # one transfer per layer
         out = np.empty((n, x.shape[1]), np.float32)
         for rows in clusters:
             if len(rows):
-                out[rows] = _prop_rows(ip, ix, dt, rows, x_pad, block)
+                out[rows] = _prop_rows(ip, ix, dt, rows, x_pad, block,
+                                       nr_pad)
         return out
 
     h: Optional[np.ndarray] = None       # None → stream graph.features

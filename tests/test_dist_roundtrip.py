@@ -11,19 +11,19 @@ def test_compressed_psum_matches_uncompressed_over_many_steps(
     out = run_distributed("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.dist.compression import compressed_psum_mean
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2,), ("data",))
+mesh = make_mesh((2,), ("data",))
 D = 128
 
 def one_step(local, err):
     m, e = compressed_psum_mean(local[0], err[0], axis_name="data", bits=8)
     return m[None], e[None]
 
-step = jax.jit(shard_map(one_step, mesh=mesh,
-                         in_specs=(P("data"), P("data")),
-                         out_specs=(P("data"), P("data"))))
+step = jax.jit(jax.shard_map(one_step, mesh=mesh,
+                             in_specs=(P("data"), P("data")),
+                             out_specs=(P("data"), P("data"))))
 
 rng = np.random.default_rng(0)
 err = jnp.zeros((2, D))
@@ -55,7 +55,9 @@ from repro.core import ClusterBatcher, GCNConfig, train_cluster_gcn
 from repro.graph import make_dataset, partition_graph
 from repro.nn import adamw
 
-mesh = jax.make_mesh((2,), ("data",))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2,), ("data",))
 g = make_dataset("cora", scale=0.3, seed=0)
 cfg = GCNConfig(in_dim=g.features.shape[1], hidden_dim=16,
                 out_dim=int(g.labels.max()) + 1, num_layers=2, dropout=0.0)

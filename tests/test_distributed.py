@@ -15,7 +15,7 @@ from repro.configs import get_arch, make_inputs, input_specs
 from repro.models.config import ShapeConfig
 from repro.dist.sharding import CellPolicy, make_rules, shardings_for, batch_pspec
 from repro.dist.steps import make_train_step, spec_train_state
-from repro.launch.mesh import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.spec import init_tree
 from repro.nn.optim import adamw
 
@@ -24,13 +24,13 @@ shape = ShapeConfig("t", "train", 32, 8)
 batch = make_inputs(cfg, shape)
 losses = {}
 for mesh_shape in [(1, 1), (4, 2)]:
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     policy = CellPolicy(fsdp=True, microbatches=2, remat=True, loss_chunk=16)
     rules = make_rules(mesh, cfg, shape, policy)
     act = P(rules.get("batch"), None, None)
     st_specs = spec_train_state(cfg)
     st_sh = shardings_for(st_specs, mesh, rules)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = jax.jit(make_train_step(cfg, policy, adamw(1e-3), act_spec=act),
                        in_shardings=(st_sh, batch_pspec(input_specs(cfg, shape), mesh, rules)),
                        out_shardings=(st_sh, None))
@@ -63,7 +63,8 @@ cfg = get_arch("llama3.2-1b", smoke=True)
 shape = ShapeConfig("t", "train", 32, 8)
 st_specs = spec_train_state(cfg)
 with tempfile.TemporaryDirectory() as d:
-    m8 = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    m8 = make_mesh((4, 2), ("data", "model"))
     rules8 = make_rules(m8, cfg, shape, CellPolicy())
     sh8 = shardings_for(st_specs, m8, rules8)
     state = init_tree(st_specs, jax.random.PRNGKey(0))
@@ -71,7 +72,7 @@ with tempfile.TemporaryDirectory() as d:
     ck = CheckpointManager(d, async_save=False)
     ck.save(7, state)
     # restore onto a smaller 2-device mesh (elastic shrink)
-    m2 = jax.make_mesh((2, 1), ("data", "model"))
+    m2 = make_mesh((2, 1), ("data", "model"))
     rules2 = make_rules(m2, cfg, shape, CellPolicy())
     sh2 = shardings_for(st_specs, m2, rules2)
     restored = ck.restore(state, shardings=sh2)
@@ -90,15 +91,15 @@ def test_gradient_compression_allreduce(run_distributed):
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist.compression import compressed_psum_mean
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 def f(local, err):
     return compressed_psum_mean(local[0], err[0], axis_name="data", bits=8)
-fn = shard_map(lambda l, e: jax.tree_util.tree_map(lambda x: x[None], f(l, e)),
-               mesh=mesh, in_specs=(P("data"), P("data")),
-               out_specs=(P("data"), P("data")))
+fn = jax.shard_map(lambda l, e: jax.tree_util.tree_map(lambda x: x[None], f(l, e)),
+                   mesh=mesh, in_specs=(P("data"), P("data")),
+                   out_specs=(P("data"), P("data")))
 out, new_err = fn(g, jnp.zeros_like(g))
 want = g.mean(0)
 got = np.asarray(out[0])

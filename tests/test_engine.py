@@ -94,7 +94,8 @@ def test_wrapper_matches_spec_engine_dp(run_distributed):
     out = run_distributed(_SUBPROCESS_PRELUDE + """
 r_spec = build_experiment(cora_spec({"execution.data_shards": 2})).fit()
 exp = build_experiment(cora_spec())     # wrapper drives the mesh itself
-mesh = jax.make_mesh((2,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("data",))
 r_wrap = train_cluster_gcn(exp.graph, exp.batcher, exp.cfg, exp.opt,
                            num_epochs=3, seed=0, eval_every=3, mesh=mesh)
 assert strip_time(r_wrap.history) == strip_time(r_spec.history), (

@@ -222,7 +222,9 @@ def test_resolved_eval_split_survives_checkpoint_resume(tmp_path):
 # the CLI driver end-to-end (train → checkpoint → resume → eval)
 # ----------------------------------------------------------------------
 def _cli(tmp_path, *argv):
-    env = dict(os.environ, PYTHONPATH=_SRC, JAX_PLATFORMS="cpu")
+    # the CLI's compile cache goes to the test's own directory
+    env = dict(os.environ, PYTHONPATH=_SRC, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"))
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.run_experiment", *argv],
         env=env, capture_output=True, text=True, timeout=560)

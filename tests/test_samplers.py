@@ -334,10 +334,13 @@ def test_saint_sparse_kslots_auto(graph):
     assert len(res.history) == 1 and np.isfinite(res.history[0]["loss"])
 
 
-def test_cli_saint_override_trains(tmp_path):
+def test_cli_saint_override_trains(tmp_path, monkeypatch):
     """Acceptance path: --preset ppi_tiny --set batch.sampler=saint_node
     trains end-to-end through the CLI and writes the artifacts."""
     from repro.launch.run_experiment import main
+    # main() keeps the persistent compile cache where this variable says;
+    # set after jax started, it leaves the cache off in this process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     rc = main(["--preset", "ppi_tiny", "--set", "batch.sampler=saint_node",
                "--set", "run.epochs=1",
                "--results-dir", str(tmp_path)])

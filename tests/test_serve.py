@@ -430,8 +430,11 @@ def test_serve_spec_round_trip_and_back_compat():
 # ----------------------------------------------------------------------
 # CLI
 # ----------------------------------------------------------------------
-def test_serve_gcn_cli_end_to_end(trained, tmp_path, capsys):
+def test_serve_gcn_cli_end_to_end(trained, tmp_path, capsys, monkeypatch):
     from repro.launch.serve_gcn import main
+    # main() keeps the persistent compile cache where this variable says;
+    # set after jax started, it leaves the cache off in this process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     spec, _ = trained
     bench = tmp_path / "BENCH_serve.json"
     rc = main(["--preset", "ppi_tiny", "--queries", "96",

@@ -105,7 +105,9 @@ from repro.core import ClusterBatcher, GCNConfig, train_cluster_gcn
 from repro.graph import make_dataset, partition_graph
 from repro.nn import adamw
 
-mesh = jax.make_mesh((2,), ("data",))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2,), ("data",))
 g = make_dataset("cora", scale=0.3, seed=0)
 cfg = GCNConfig(in_dim=g.features.shape[1], hidden_dim=16,
                 out_dim=int(g.labels.max()) + 1, num_layers=2, dropout=0.0)

@@ -294,7 +294,9 @@ from repro.core import ClusterBatcher, GCNConfig, train_cluster_gcn
 from repro.graph import make_dataset, partition_graph
 from repro.nn import adamw
 
-mesh = jax.make_mesh((2,), ("data",))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2,), ("data",))
 g = make_dataset("ppi", scale=0.03, seed=0)
 parts, _ = partition_graph(g, 8, method="metis", seed=0)
 cfg_kw = dict(in_dim=g.features.shape[1], hidden_dim=32,
