@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.graph.csr import CSRGraph
 from repro.graph.normalization import normalize_csr, normalize_dense
+from repro.runtime import tracing
 
 Array = np.ndarray
 
@@ -170,78 +171,86 @@ def subgraph_payload(graph: CSRGraph, nodes: Array, *, node_cap: int,
         raise ValueError("k_slots='auto' needs a pre-computed k_plan "
                          "(repro.core.kslots.plan_k_buckets) — samplers "
                          "build one at init")
-    sub, _ = graph.subgraph(nodes)  # re-adds Δ links among chosen nodes
+    with tracing.span("batch.slice"):
+        sub, _ = graph.subgraph(nodes)  # re-adds Δ links among nodes
     b = len(nodes)
     cap = node_cap
 
-    if sparse_adj:
-        # normalize the batch CSR directly (paper §6.2) and tile it —
-        # the dense (cap, cap) block is never materialized. K follows
-        # the k_slots policy: "cap" pins the lossless worst case
-        # cap/B; "auto" picks the smallest pre-planned bucket that
-        # holds this batch losslessly (repro.core.kslots); an int is
-        # used as-is (builders raise if it would drop tiles).
-        from repro.kernels.ops import block_ell_adj_from_csr
-        ip, ix, dt = normalize_csr(sub.indptr, sub.indices, sub.data,
-                                   norm, diag_lambda)
-        if k_slots == "auto":
-            # bucket picked inside the builder from the occupancy it
-            # computes anyway — no extra O(nnz) pass per batch
-            chooser = lambda nf, nt: \
-                k_plan.bucket_for(max(nf, nt, 1))  # noqa: E731
-            adj = block_ell_adj_from_csr(ip, ix, dt, n_cols=cap,
-                                         block=block_size,
-                                         n_rows=cap,
-                                         assume_unique=True,
-                                         k_chooser=chooser,
-                                         pool=tile_pool)
-        else:
-            k = cap // block_size if k_slots == "cap" else int(k_slots)
-            adj = block_ell_adj_from_csr(ip, ix, dt, n_cols=cap,
-                                         block=block_size,
-                                         k_slots=k, k_slots_t=k,
-                                         n_rows=cap,
-                                         assume_unique=True,
-                                         pool=tile_pool)
-    else:
-        dense = np.zeros((cap, cap), np.float32)
-        row = np.repeat(np.arange(b), np.diff(sub.indptr))
-        dense[row, sub.indices] = sub.data
-        # re-normalize the combined adjacency (paper §6.2)
-        dense[:b, :b] = normalize_dense(dense[:b, :b], norm, diag_lambda)
-        dense[b:, :] = 0.0
-        dense[:, b:] = 0.0
-        adj = dense
-
-    feat_dim = graph.features.shape[1]
-    feats = np.zeros((cap, feat_dim), np.float32)
-    feats[:b] = graph.features[nodes]
-    if precompute_ax:
-        # host-side Â'·X (paper §6.2): aggregate once per batch, in fp32
-        # regardless of the training compute dtype; padding rows stay 0
+    with tracing.span("batch.adjacency"):
         if sparse_adj:
-            import scipy.sparse as sp
-            feats[:b] = sp.csr_matrix((dt, ix, ip),
-                                      shape=(b, b)) @ feats[:b]
+            # normalize the batch CSR directly (paper §6.2) and tile
+            # it — the dense (cap, cap) block is never materialized. K
+            # follows the k_slots policy: "cap" pins the lossless worst
+            # case cap/B; "auto" picks the smallest pre-planned bucket
+            # that holds this batch losslessly (repro.core.kslots); an
+            # int is used as-is (the tiling raises if it would drop
+            # tiles).
+            from repro.kernels.ops import block_ell_adj_from_csr
+            ip, ix, dt = normalize_csr(sub.indptr, sub.indices, sub.data,
+                                       norm, diag_lambda)
+            if k_slots == "auto":
+                # bucket picked inside block_ell_adj_from_csr from the
+                # occupancy it computes anyway — no extra O(nnz) pass
+                # per batch
+                chooser = lambda nf, nt: \
+                    k_plan.bucket_for(max(nf, nt, 1))  # noqa: E731
+                adj = block_ell_adj_from_csr(ip, ix, dt, n_cols=cap,
+                                             block=block_size,
+                                             n_rows=cap,
+                                             assume_unique=True,
+                                             k_chooser=chooser,
+                                             pool=tile_pool)
+            else:
+                k = (cap // block_size if k_slots == "cap"
+                     else int(k_slots))
+                adj = block_ell_adj_from_csr(ip, ix, dt, n_cols=cap,
+                                             block=block_size,
+                                             k_slots=k, k_slots_t=k,
+                                             n_rows=cap,
+                                             assume_unique=True,
+                                             pool=tile_pool)
         else:
-            feats[:b] = adj[:b, :b] @ feats[:b]
+            dense = np.zeros((cap, cap), np.float32)
+            row = np.repeat(np.arange(b), np.diff(sub.indptr))
+            dense[row, sub.indices] = sub.data
+            # re-normalize the combined adjacency (paper §6.2)
+            dense[:b, :b] = normalize_dense(dense[:b, :b], norm,
+                                            diag_lambda)
+            dense[b:, :] = 0.0
+            dense[:, b:] = 0.0
+            adj = dense
 
-    labels_src = graph.labels
-    if labels_src.ndim == 1:
-        labels = np.zeros((cap,), np.int32)
-    else:
-        labels = np.zeros((cap, labels_src.shape[1]), np.float32)
-    labels[:b] = labels_src[nodes]
+    with tracing.span("batch.gather"):
+        feat_dim = graph.features.shape[1]
+        feats = np.zeros((cap, feat_dim), np.float32)
+        feats[:b] = graph.features[nodes]
+        if precompute_ax:
+            # host-side Â'·X (paper §6.2): aggregate once per batch, in
+            # fp32 regardless of the training compute dtype; padding
+            # rows stay 0
+            if sparse_adj:
+                import scipy.sparse as sp
+                feats[:b] = sp.csr_matrix((dt, ix, ip),
+                                          shape=(b, b)) @ feats[:b]
+            else:
+                feats[:b] = adj[:b, :b] @ feats[:b]
 
-    node_mask = np.zeros(cap, bool)
-    node_mask[:b] = True
-    loss_mask = np.zeros(cap, np.float32)
-    if graph.train_mask is not None:
-        loss_mask[:b] = graph.train_mask[nodes].astype(np.float32)
-    else:
-        loss_mask[:b] = 1.0
-    if loss_weights is not None:
-        loss_mask[:b] *= np.asarray(loss_weights, np.float32)
+        labels_src = graph.labels
+        if labels_src.ndim == 1:
+            labels = np.zeros((cap,), np.int32)
+        else:
+            labels = np.zeros((cap, labels_src.shape[1]), np.float32)
+        labels[:b] = labels_src[nodes]
+
+        node_mask = np.zeros(cap, bool)
+        node_mask[:b] = True
+        loss_mask = np.zeros(cap, np.float32)
+        if graph.train_mask is not None:
+            loss_mask[:b] = graph.train_mask[nodes].astype(np.float32)
+        else:
+            loss_mask[:b] = 1.0
+        if loss_weights is not None:
+            loss_mask[:b] *= np.asarray(loss_weights, np.float32)
     return ClusterBatch(adj=adj, features=feats, labels=labels,
                         node_mask=node_mask, loss_mask=loss_mask,
                         num_real=np.int32(b))
@@ -402,15 +411,14 @@ class ClusterBatcher:
     def _build(self, cluster_ids: Sequence[int], *,
                rng_ctx: Tuple[int, int],
                tile_pool) -> ClusterBatch:
-        nodes = self._batch_nodes(cluster_ids, rng_ctx=rng_ctx)
-        return subgraph_payload(self.graph, nodes, node_cap=self.node_cap,
-                                norm=self.norm,
-                                diag_lambda=self.diag_lambda,
-                                sparse_adj=self.sparse_adj,
-                                block_size=self.block_size,
-                                k_slots=self.k_slots, k_plan=self.k_plan,
-                                precompute_ax=self.precompute_ax,
-                                tile_pool=tile_pool)
+        with tracing.span("batch.build"):
+            nodes = self._batch_nodes(cluster_ids, rng_ctx=rng_ctx)
+            return subgraph_payload(
+                self.graph, nodes, node_cap=self.node_cap, norm=self.norm,
+                diag_lambda=self.diag_lambda, sparse_adj=self.sparse_adj,
+                block_size=self.block_size, k_slots=self.k_slots,
+                k_plan=self.k_plan, precompute_ax=self.precompute_ax,
+                tile_pool=tile_pool)
 
     # ------------------------------------------------------------------
     def epoch(self, epoch_idx: int,

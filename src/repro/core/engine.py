@@ -56,7 +56,7 @@ from repro.core.prefetch import prefetch_iter
 from repro.kernels.ops import spmm as spmm_dispatch
 from repro.kernels.ops import spmm_xw as spmm_xw_dispatch
 from repro.nn.optim import Optimizer, apply_updates
-from repro.runtime import faults
+from repro.runtime import faults, tracing
 from repro.runtime.resilience import StragglerDetector
 
 PyTree = Any
@@ -98,8 +98,9 @@ def make_train_step(cfg: GCNConfig, opt: Optimizer,
             (loss, aux), grads = jax.value_and_grad(gcn_loss, has_aux=True)(
                 params, batch_tuple, cfg, train=True, rng=sub,
                 spmm=spmm, spmm_xw=spmm_xw)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = apply_updates(params, updates)
+            with jax.named_scope("optim.update"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = apply_updates(params, updates)
             return params, opt_state, rng, loss, aux
         return faults.wrap_step_faults(jax.jit(step, donate_argnums=(0, 1)))
 
@@ -115,8 +116,9 @@ def make_train_step(cfg: GCNConfig, opt: Optimizer,
                                        scale_state["scale"])
         grads = unscale_grads(grads, scale_state["scale"])
         finite = all_finite(grads)
-        updates, new_opt = opt.update(grads, opt_state, params)
-        new_params = apply_updates(params, updates)
+        with jax.named_scope("optim.update"):
+            updates, new_opt = opt.update(grads, opt_state, params)
+            new_params = apply_updates(params, updates)
         params = select_tree(finite, new_params, params)
         opt_state = select_tree(finite, new_opt, opt_state)
         scale_state = update_scale_state(scale_state, finite, pol)
@@ -554,6 +556,8 @@ class Engine:
         self._finite_losses: List[float] = []
         # current resume point: (epoch, step_in_epoch, losses, auxes)
         self._position: Tuple[int, int, list, list] = (0, 0, [], [])
+        # device scalars read back by the current epoch's record
+        self._reads = 0
 
     # -- state ----------------------------------------------------------
     def init_state(self) -> PyTree:
@@ -827,35 +831,53 @@ class Engine:
                         return (b.astuple() for b in self.batcher.epoch(
                             _e, start_step=_s + consumed))
                 flagged = 0
-                for payload in prefetch_iter(
-                        stream, effective, transfer=transfer,
-                        hang_timeout=self.prefetch_timeout,
-                        rebuild=rebuild):
-                    t_step = time.perf_counter()
-                    self.state, loss, aux = self.backend.step(self.state,
-                                                              payload)
-                    losses.append(loss)
-                    auxes.append(aux)
-                    self.global_step += 1
-                    step_in_epoch += 1
-                    self._position = (epoch, step_in_epoch, losses, auxes)
-                    dt_step = time.perf_counter() - t_step
-                    step_total += dt_step
-                    if self.straggler.flag_step(dt_step):
-                        flagged += 1
-                    if self._guards_on:
-                        self._check_divergence(loss)
-                    if faults.maybe_fail("sigterm.at_step",
-                                         index=self.global_step):
-                        # after the step completed, before hooks see it —
-                        # exactly where a scheduler's kill usually lands
-                        _signal.raise_signal(_signal.SIGTERM)
-                    self._fire("on_step", {"epoch": epoch,
-                                           "step_in_epoch": step_in_epoch,
-                                           "global_step": self.global_step,
-                                           "loss": loss, "aux": aux})
-                    if self._stop:
-                        break
+                payloads = prefetch_iter(
+                    stream, effective, transfer=transfer,
+                    hang_timeout=self.prefetch_timeout, rebuild=rebuild)
+                try:
+                    while True:
+                        t_loop = time.perf_counter()
+                        with tracing.span("engine.wait"):
+                            payload = next(payloads, None)
+                        if payload is None:
+                            break
+                        with tracing.span("engine.step"):
+                            t_step = time.perf_counter()
+                            self.state, loss, aux = self.backend.step(
+                                self.state, payload)
+                            if measuring:
+                                # the device's time, not the enqueue's
+                                jax.block_until_ready(loss)
+                                step_total += time.perf_counter() - t_step
+                        losses.append(loss)
+                        auxes.append(aux)
+                        self.global_step += 1
+                        step_in_epoch += 1
+                        self._position = (epoch, step_in_epoch, losses,
+                                          auxes)
+                        if self._guards_on:
+                            self._check_divergence(loss)
+                        if faults.maybe_fail("sigterm.at_step",
+                                             index=self.global_step):
+                            # after the step completed, before hooks see
+                            # it — exactly where a scheduler's kill
+                            # usually lands
+                            _signal.raise_signal(_signal.SIGTERM)
+                        with tracing.span("engine.hooks"):
+                            self._fire("on_step", {
+                                "epoch": epoch,
+                                "step_in_epoch": step_in_epoch,
+                                "global_step": self.global_step,
+                                "loss": loss, "aux": aux})
+                        # the host loop's period: wait, step and hooks
+                        if self.straggler.flag_step(time.perf_counter()
+                                                    - t_loop):
+                            flagged += 1
+                        if self._stop:
+                            break
+                finally:
+                    # stops a prefetch producer now, not at collection
+                    payloads.close()
                 if self._stop:
                     self.preempted = True
                     if not self._skip_stop_checkpoint:
@@ -874,7 +896,8 @@ class Engine:
                 self.history.append(rec)
                 self._position = (epoch + 1, 0, [], [])
                 losses, auxes = [], []
-                self._fire("on_epoch", rec)
+                with tracing.span("engine.hooks"):
+                    self._fire("on_epoch", rec)
                 if self._stop:          # stop requested by an epoch hook
                     self.preempted = True
                     if not self._skip_stop_checkpoint:
@@ -904,22 +927,32 @@ class Engine:
                            params=self.backend.params(self.state),
                            seconds=time.perf_counter() - t0)
 
+    def _read_back(self, x) -> float:
+        """One device-to-host read of a scalar, counted in `_reads` for
+        the `engine.epoch_end` span's `syncs` stat."""
+        self._reads += 1
+        return float(x)
+
     def _epoch_record(self, epoch: int, losses, auxes, t0,
                       flagged: int = 0) -> Dict:
-        rec = {"epoch": epoch,
-               "loss": float(np.mean([float(l) for l in losses])),
-               "time": time.perf_counter() - t0,
-               # straggler diagnostic (StragglerDetector.flag_step):
-               # wall-time-derived, so resumed-run histories may differ
-               # here (tests strip it like "time")
-               "flagged_steps": flagged}
-        if self.cfg.multilabel:
-            tp = sum(float(a["tp"]) for a in auxes)
-            fp = sum(float(a["fp"]) for a in auxes)
-            fn = sum(float(a["fn"]) for a in auxes)
-            rec["train_f1"] = micro_f1(tp, fp, fn)
-        else:
-            c = sum(float(a["correct"]) for a in auxes)
-            n = sum(float(a["n"]) for a in auxes)
-            rec["train_acc"] = c / max(n, 1.0)
+        self._reads = 0
+        with tracing.span("engine.epoch_end", steps=len(losses)) as span:
+            read = self._read_back
+            rec = {"epoch": epoch,
+                   "loss": float(np.mean([read(l) for l in losses])),
+                   "time": time.perf_counter() - t0,
+                   # straggler diagnostic (StragglerDetector.flag_step):
+                   # wall-time-derived, so resumed-run histories may
+                   # differ here (tests strip it like "time")
+                   "flagged_steps": flagged}
+            if self.cfg.multilabel:
+                tp = sum(read(a["tp"]) for a in auxes)
+                fp = sum(read(a["fp"]) for a in auxes)
+                fn = sum(read(a["fn"]) for a in auxes)
+                rec["train_f1"] = micro_f1(tp, fp, fn)
+            else:
+                c = sum(read(a["correct"]) for a in auxes)
+                n = sum(read(a["n"]) for a in auxes)
+                rec["train_acc"] = c / max(n, 1.0)
+            span.set_metadata(syncs=self._reads)
         return rec

@@ -273,10 +273,11 @@ class ExecutionSpec:
     prefetch: Union[int, str] = _f(
         0, "batches built ahead on a background thread (incl. DP "
         "stacking + device_put); 0 is fully synchronous, 'auto' "
-        "measures the host-build/device-step time ratio during a "
-        "synchronous warmup epoch and picks the depth itself (logged "
-        "per epoch as prefetch_depth/host_build_over_step in history "
-        "rows) — trajectories are identical for every setting")
+        "measures over a synchronous warmup epoch the host build time "
+        "over each step's time to its loss (synced in that epoch only) "
+        "and picks the depth itself (logged per epoch as "
+        "prefetch_depth/host_build_over_step in history rows) — "
+        "trajectories are identical for every setting")
     prefetch_timeout_s: float = _f(600.0, "seconds a training step may "
                                    "wait on the prefetch producer before "
                                    "the run aborts with a diagnosable "

@@ -111,30 +111,37 @@ def gcn_forward(params: PyTree, adj, x: jnp.ndarray,
         else:
             keys.append(None)
 
+    # named scopes (repro.runtime.tracing) label the device ops of each
+    # part of a layer in a profiler trace, backward ops included
     def layer_fn(i, h, layer, key):
         if need_dropout:
-            keep = 1.0 - cfg.dropout
-            h = h * jax.random.bernoulli(key, keep, h.shape) / keep
+            with jax.named_scope("gcn.dropout"):
+                keep = 1.0 - cfg.dropout
+                h = h * jax.random.bernoulli(key, keep, h.shape) / keep
         propagate = not (i == 0 and cfg.precompute_ax)
         if cfg.fuse_spmm and propagate:
             # fused Â·(XW + b): one seam, no XW materialization between
             # the two products. Same math contract as the unfused branch
             # (operands in cd, fp32 accumulation, fp32 bias add) — in
             # fp32 the two branches are value-identical.
-            z = spmm_xw(adj, h.astype(cd), layer["w"], layer["b"])
+            with jax.named_scope("gcn.xw_aggregate"):
+                z = spmm_xw(adj, h.astype(cd), layer["w"], layer["b"])
         else:
-            z = (jnp.matmul(h.astype(cd), layer["w"].astype(cd),   # X W
-                            preferred_element_type=jnp.float32)
-                 + layer["b"]).astype(cd)
+            with jax.named_scope("gcn.xw"):
+                z = (jnp.matmul(h.astype(cd), layer["w"].astype(cd),
+                                preferred_element_type=jnp.float32)
+                     + layer["b"]).astype(cd)
             if propagate:                # Â (XW): (b, b)·(b, F')
-                z = spmm(adj, z)
+                with jax.named_scope("gcn.aggregate"):
+                    z = spmm(adj, z)
         if i < n - 1:
-            if cfg.residual and z.shape == h.shape:
-                z = z + h.astype(z.dtype)        # paper Eq. 8
-            z = jax.nn.relu(z)
-            if cfg.layernorm:
-                z = _layernorm(z.astype(jnp.float32),
-                               layer["ln_scale"]).astype(cd)
+            with jax.named_scope("gcn.activation"):
+                if cfg.residual and z.shape == h.shape:
+                    z = z + h.astype(z.dtype)        # paper Eq. 8
+                z = jax.nn.relu(z)
+                if cfg.layernorm:
+                    z = _layernorm(z.astype(jnp.float32),
+                                   layer["ln_scale"]).astype(cd)
         return z
 
     def chunk_fn(h, chunk_layers, chunk_keys, start):
@@ -170,25 +177,26 @@ def gcn_loss(params: PyTree, batch_tuple, cfg: GCNConfig, *,
     adj, feats, labels, node_mask, loss_mask, num_real = batch_tuple
     logits = gcn_forward(params, adj, feats, cfg, train=train, rng=rng,
                          spmm=spmm, spmm_xw=spmm_xw)
-    denom = jnp.maximum(loss_mask.sum(), 1.0)
-    if cfg.multilabel:
-        y = labels.astype(jnp.float32)
-        ll = jnp.maximum(logits, 0) - logits * y + jnp.log1p(
-            jnp.exp(-jnp.abs(logits)))
-        loss = (ll.sum(-1) * loss_mask).sum() / denom
-        pred = (logits > 0).astype(jnp.float32)
-        tp = (pred * y * loss_mask[:, None]).sum()
-        fp = (pred * (1 - y) * loss_mask[:, None]).sum()
-        fn = ((1 - pred) * y * loss_mask[:, None]).sum()
-        aux = {"tp": tp, "fp": fp, "fn": fn, "n": denom}
-    else:
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-        nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32),
-                                   axis=-1)[:, 0]
-        loss = (nll * loss_mask).sum() / denom
-        correct = (logits.argmax(-1) == labels).astype(jnp.float32)
-        aux = {"correct": (correct * loss_mask).sum(), "n": denom}
-    return loss, aux
+    with jax.named_scope("gcn.loss"):
+        denom = jnp.maximum(loss_mask.sum(), 1.0)
+        if cfg.multilabel:
+            y = labels.astype(jnp.float32)
+            ll = jnp.maximum(logits, 0) - logits * y + jnp.log1p(
+                jnp.exp(-jnp.abs(logits)))
+            loss = (ll.sum(-1) * loss_mask).sum() / denom
+            pred = (logits > 0).astype(jnp.float32)
+            tp = (pred * y * loss_mask[:, None]).sum()
+            fp = (pred * (1 - y) * loss_mask[:, None]).sum()
+            fn = ((1 - pred) * y * loss_mask[:, None]).sum()
+            aux = {"tp": tp, "fp": fp, "fn": fn, "n": denom}
+        else:
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+            nll = -jnp.take_along_axis(
+                logp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
+            loss = (nll * loss_mask).sum() / denom
+            correct = (logits.argmax(-1) == labels).astype(jnp.float32)
+            aux = {"correct": (correct * loss_mask).sum(), "n": denom}
+        return loss, aux
 
 
 def micro_f1(tp: float, fp: float, fn: float) -> float:

@@ -34,12 +34,13 @@ import queue
 import threading
 from typing import Callable, Iterable, Iterator, Optional, TypeVar
 
-from repro.runtime import faults
+from repro.runtime import faults, tracing
 from repro.runtime.resilience import HeartbeatMonitor
 
 T = TypeVar("T")
 
 _ITEM, _DONE, _ERR = 0, 1, 2
+_END = object()
 
 
 class PrefetchError(RuntimeError):
@@ -100,15 +101,22 @@ def prefetch_iter(src: Iterable[T], size: int = 2,
     def _produce(source) -> None:
         try:
             hb.beat(0)
-            for item in source:
-                hb.beat(0)
-                if faults.maybe_fail("prefetch.producer_crash"):
-                    return          # dies silently: no _DONE, no _ERR
-                if faults.maybe_fail("prefetch.producer_hang"):
-                    stop.wait()     # alive but silent until shutdown
-                    return
-                if transfer is not None:
-                    item = transfer(item)
+            while True:
+                # one span per item: its build and its transfer, not the
+                # wait for room in the queue
+                with tracing.span("prefetch.produce"):
+                    item = next(source, _END)
+                    if item is _END:
+                        break
+                    hb.beat(0)
+                    if faults.maybe_fail("prefetch.producer_crash"):
+                        return      # dies silently: no _DONE, no _ERR
+                    if faults.maybe_fail("prefetch.producer_hang"):
+                        stop.wait()     # alive but silent until shutdown
+                        return
+                    if transfer is not None:
+                        with tracing.span("prefetch.transfer"):
+                            item = transfer(item)
                 if not _put((_ITEM, item)):
                     return
             _put((_DONE, None))

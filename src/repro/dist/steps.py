@@ -322,26 +322,28 @@ def make_gcn_train_step(cfg: GCNConfig, opt: Optimizer, mesh, *,
             grads = unscale_grads(grads, scale)
 
         new_state = dict(state)
-        if bits is not None:
-            flat_g, treedef = jax.tree_util.tree_flatten(grads)
-            flat_e = jax.tree_util.tree_leaves(state["err"])
-            synced = [compressed_psum_mean(g, e[0], axis_name, bits=bits,
-                                           group_size=gsize)
-                      for g, e in zip(flat_g, flat_e)]
-            grads = jax.tree_util.tree_unflatten(
-                treedef, [s[0] for s in synced])
-            new_state["err"] = jax.tree_util.tree_unflatten(
-                treedef, [s[1][None] for s in synced])
-        elif compression == "bf16":
-            grads = jax.tree_util.tree_map(
-                lambda g: bf16_psum_mean(g, axis_name), grads)
-        else:
-            grads = jax.tree_util.tree_map(
-                lambda g: psum_mean(g, axis_name), grads)
+        with jax.named_scope("dp.allreduce"):
+            if bits is not None:
+                flat_g, treedef = jax.tree_util.tree_flatten(grads)
+                flat_e = jax.tree_util.tree_leaves(state["err"])
+                synced = [compressed_psum_mean(g, e[0], axis_name,
+                                               bits=bits, group_size=gsize)
+                          for g, e in zip(flat_g, flat_e)]
+                grads = jax.tree_util.tree_unflatten(
+                    treedef, [s[0] for s in synced])
+                new_state["err"] = jax.tree_util.tree_unflatten(
+                    treedef, [s[1][None] for s in synced])
+            elif compression == "bf16":
+                grads = jax.tree_util.tree_map(
+                    lambda g: bf16_psum_mean(g, axis_name), grads)
+            else:
+                grads = jax.tree_util.tree_map(
+                    lambda g: psum_mean(g, axis_name), grads)
 
         # identical on every shard after the all-reduce
-        updates, opt_state = opt.update(grads, state["opt"], params)
-        new_params = apply_updates(params, updates)
+        with jax.named_scope("optim.update"):
+            updates, opt_state = opt.update(grads, state["opt"], params)
+            new_params = apply_updates(params, updates)
         if pol.scaled:
             # post-sync grads are nan everywhere if ANY shard
             # overflowed, so the skip is mesh-consistent

@@ -69,10 +69,13 @@ class StragglerDetector:
     Single-host runs use `flag_step` instead: with one host, `record`
     compares the host's EWMA against the median of itself and can never
     flag, so per-STEP wall times are compared against their own
-    trailing median — the Engine feeds every step's duration in and
-    counts flagged steps per epoch into the history rows
-    (`flagged_steps`), which is how a degrading disk or a noisy
-    neighbor shows up in metrics.json before it kills throughput."""
+    trailing median. The Engine feeds in its host loop's period for
+    each step (waiting for the batch, dispatching the step, running the
+    hooks) and counts flagged steps per epoch into the history rows
+    (`flagged_steps`). It flags host-side stalls: a slow batch build, a
+    degrading disk or a noisy neighbor shows up in metrics.json before
+    it kills throughput. The step is dispatched, not waited on, so a
+    slower device shows only once it holds the host back."""
     alpha: float = 0.2
     threshold: float = 1.5
     window: int = 64
@@ -85,9 +88,10 @@ class StragglerDetector:
 
     def flag_step(self, seconds: float) -> bool:
         """Single-host per-step variant of record(): True when this
-        step took more than `threshold` × the trailing median of the
-        last `window` steps (after `warmup` steps have been seen —
-        jit compilation makes the first steps pathological)."""
+        step's host loop period took more than `threshold` × the
+        trailing median of the last `window` steps (after `warmup`
+        steps have been seen — jit compilation makes the first steps
+        pathological)."""
         hist = self._step_hist
         flagged = bool(
             len(hist) >= self.warmup

@@ -224,3 +224,33 @@ def test_mid_epoch_resume_uses_seam_trajectory(tmp_path):
     r = resumed.fit(resume=True)
     assert _strip_time(r.history) == _strip_time(straight.history)
     _assert_params_equal(r.params, straight.params)
+
+
+# ----------------------------------------------------------------------
+# the straggler detector's clock
+# ----------------------------------------------------------------------
+def test_straggler_detector_is_fed_the_host_loop_period():
+    """flag_step sees each step's whole host loop: the wait for its
+    batch, the step's dispatch and the hooks. A hook that stalls one
+    step is flagged; the dispatch time alone would not show it."""
+    import time
+
+    class Stall:
+        def __init__(self):
+            self.fed = []
+
+        def on_step(self, engine, info):
+            time.sleep(0.3 if info["global_step"] == 11 else 0.01)
+
+    spec = preset("ppi_tiny")
+    spec.run.epochs = 3
+    spec.run.eval_every = 0
+    stall = Stall()
+    exp = build_experiment(spec, extra_hooks=[stall])
+    flag = exp.engine.straggler.flag_step
+    exp.engine.straggler.flag_step = \
+        lambda s: stall.fed.append(s) or flag(s)
+    res = exp.fit()
+    assert len(stall.fed) == exp.engine.global_step == 12
+    assert min(stall.fed) >= 0.01 and stall.fed[10] >= 0.3
+    assert res.history[2]["flagged_steps"] >= 1

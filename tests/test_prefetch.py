@@ -214,3 +214,28 @@ def test_prefetch_auto_spec_validation():
         spec.execution.prefetch = bad
         with pytest.raises(ValueError, match="execution.prefetch"):
             validate(spec)
+
+
+def test_prefetch_auto_divides_by_the_synced_step(monkeypatch):
+    """The warmup epoch's host_build_over_step divides the build time by
+    each step's time to its loss (block_until_ready), not by the time to
+    dispatch it; later epochs, and runs at a fixed depth, never wait on
+    a step."""
+    from repro.core.experiment import build_experiment, preset
+
+    waited = []
+    block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waited.append(x) or block(x))
+    for pf in (0, 2, "auto"):
+        spec = preset("ppi_tiny")
+        spec.run.epochs = 3
+        spec.run.eval_every = 0
+        spec.execution.prefetch = pf
+        exp = build_experiment(spec)
+        del waited[:]
+        res = exp.fit()
+        steps = exp.batcher.steps_per_epoch()
+        assert len(waited) == (steps if pf == "auto" else 0), pf
+        assert all(jnp.shape(x) == () for x in waited)
+    assert res.history[0]["host_build_over_step"] > 0
