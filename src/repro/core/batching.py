@@ -33,7 +33,8 @@ from typing import (Iterator, List, Optional, Protocol, Sequence, Tuple,
 import numpy as np
 
 from repro.graph.csr import CSRGraph
-from repro.graph.normalization import normalize_csr, normalize_dense
+from repro.graph.normalization import (normalize_csr,
+                                       normalized_dense_block)
 from repro.runtime import tracing
 
 Array = np.ndarray
@@ -210,15 +211,10 @@ def subgraph_payload(graph: CSRGraph, nodes: Array, *, node_cap: int,
                                              assume_unique=True,
                                              pool=tile_pool)
         else:
-            dense = np.zeros((cap, cap), np.float32)
-            row = np.repeat(np.arange(b), np.diff(sub.indptr))
-            dense[row, sub.indices] = sub.data
-            # re-normalize the combined adjacency (paper §6.2)
-            dense[:b, :b] = normalize_dense(dense[:b, :b], norm,
-                                            diag_lambda)
-            dense[b:, :] = 0.0
-            dense[:, b:] = 0.0
-            adj = dense
+            # re-normalize the combined adjacency (paper §6.2) on its
+            # non-zeros and scatter them into the zeroed (cap, cap) block
+            adj = normalized_dense_block(sub.indptr, sub.indices, sub.data,
+                                         cap, norm, diag_lambda)
 
     with tracing.span("batch.gather"):
         feat_dim = graph.features.shape[1]

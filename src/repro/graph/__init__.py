@@ -7,7 +7,8 @@ from repro.graph.partition import (partition_graph, metis_like_partition,
                                    default_partition_cache_dir)
 from repro.graph.datasets import (REAL_DATASETS, load_dataset, cache_root,
                                   dataset_meta)
-from repro.graph.normalization import normalize_dense, normalize_csr
+from repro.graph.normalization import (normalize_dense, normalize_csr,
+                                       normalized_dense_block)
 
 __all__ = [
     "CSRGraph", "edge_cut", "within_cut_fraction",
@@ -17,5 +18,5 @@ __all__ = [
     "PartitionStats", "PARTITIONER_VERSION", "graph_fingerprint",
     "default_partition_cache_dir",
     "REAL_DATASETS", "load_dataset", "cache_root", "dataset_meta",
-    "normalize_dense", "normalize_csr",
+    "normalize_dense", "normalize_csr", "normalized_dense_block",
 ]

@@ -1,8 +1,12 @@
 """Adjacency normalization variants from the paper.
 
-All variants operate on *dense* cluster-batch adjacency blocks (that is
-where Cluster-GCN does its compute) and have CSR twins for full-graph
-baselines.
+Every variant is a per-row (or per-row-and-column) scaling plus a
+diagonal, so each comes in three forms: `normalize_dense` on a dense
+(b, b) block, `normalize_csr` on a CSR matrix (full-graph baselines and
+the block-ELL batch path), and `normalized_dense_block`, which
+normalizes a batch's CSR non-zeros in O(nnz) and scatters them into the
+zero-padded (cap, cap) block the dense training path ships to the device
+(that is where Cluster-GCN does its compute).
 
   eq1   : A' = D^{-1} A            (mean aggregator used in §4.1)
   sym   : D^{-1/2}(A+I)D^{-1/2}    (Kipf & Welling; for reference)
@@ -47,6 +51,56 @@ def normalize_dense(adj: np.ndarray, method: str = "eq10",
     else:
         raise ValueError(f"unknown normalization {method!r}")
     return out.astype(np.float32)
+
+
+def normalized_dense_block(indptr, indices, data, cap: int,
+                           method: str = "eq10",
+                           diag_lambda: float = 0.0) -> np.ndarray:
+    """Normalize a (b, b) CSR batch adjacency and return it densified
+    into a fresh, zero-padded (cap, cap) float32 block, b = len(indptr)-1.
+
+    The same block as densifying the CSR and calling `normalize_dense` on
+    its (b, b) corner, bit for bit when the weights are integer-valued
+    (their float32 row sums are exact), at O(nnz) plus one memset of the
+    block instead of several passes over b^2. Each entry repeats
+    `normalize_dense`'s float32 operations in its order; the diagonal of
+    the +I methods is (self-loop weight + 1), one rounding, like `a + eye`.
+    The (row, col) slots must be unique: `CSRGraph.from_edges` and
+    `append_graph` dedupe them, `CSRGraph.subgraph` keeps them unique, and
+    the block-ELL batch path assumes the same.
+    """
+    if method not in ("eq1", "sym", "eq10", "eq9", "eq11"):
+        raise ValueError(f"unknown normalization {method!r}")
+    b = len(indptr) - 1
+    out = np.zeros((cap, cap), np.float32)
+    out_flat = out.reshape(-1)
+    row = np.repeat(np.arange(b, dtype=np.intp), np.diff(indptr))
+    col = np.asarray(indices, dtype=np.intp)
+    val = np.asarray(data, dtype=np.float32)
+    flat = row * cap + col
+    deg = np.bincount(row, weights=val, minlength=b).astype(np.float32)
+    one = np.float32(1.0)
+    if method == "eq1":
+        out_flat[flat] = val / np.maximum(deg, np.float32(_EPS))[row]
+        return out
+    diag_a = np.ones(b, np.float32)          # diagonal of A + I
+    loop = row == col
+    diag_a[row[loop]] = val[loop] + one
+    if method == "sym":
+        dinv = one / np.sqrt(np.maximum(deg + one, np.float32(_EPS)))
+        out_flat[flat] = dinv[row] * val * dinv[col]
+        diag = dinv * diag_a * dinv
+    else:
+        scale = deg + one
+        out_flat[flat] = val / scale[row]
+        diag = diag_a / scale
+        if method == "eq9":
+            diag = diag + one
+        elif method == "eq11":
+            diag = diag + np.float32(diag_lambda) * diag
+    # written after the edges, so it replaces any self-loop slot
+    out_flat[np.arange(b) * (cap + 1)] = diag
+    return out
 
 
 def normalize_csr(indptr, indices, data, method: str = "eq10",
