@@ -138,6 +138,8 @@ def _preset_row(name: str) -> str:
         sampler = (f"{b.sampler}, budget="
                    f"{b.budget if b.budget is not None else 'auto'}")
     model = f"{m.num_layers}×{m.hidden_dim}"
+    if m.arch == "gat":
+        model = f"GAT {m.num_layers}×{m.hidden_dim}, heads {m.heads}"
     norm = b.norm + (f" (λ={b.diag_lambda:g})" if b.diag_lambda else "")
     loss = ("BCE" if m.multilabel else
             "CE" if m.multilabel is not None else "inferred")

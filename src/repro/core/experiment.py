@@ -18,7 +18,8 @@ Sections (all plain dataclasses, JSON ↔ dataclass via to_json/from_json):
              node_cap, sparse_adj, block_size, k_slots, batcher seed
              (repro.core.batching.ClusterBatcher /
              repro.core.samplers.Saint*Sampler)
-  model      GCNConfig fields, including the precision/memory policy
+  model      GCNConfig fields: the layer (arch "gcn" | "gat" and its
+             heads), the precision/memory policy
              (precision, loss_scaling, loss_scale, remat, remat_chunk —
              repro.core.precision); in_dim/out_dim/multilabel of None
              are inferred from the materialized graph
@@ -77,6 +78,7 @@ _OPTIMIZERS = ("adamw", "sgd")
 _SAMPLERS = ("cluster", "saint_node", "saint_edge")
 _PRECISIONS = ("fp32", "bf16")
 _LOSS_SCALINGS = ("none", "static", "dynamic")
+_ARCHS = ("gcn", "gat")
 
 
 def _f(default: Any, doc: str) -> Any:
@@ -192,7 +194,16 @@ class BatchSpec:
 class ModelSpec:
     """GCN architecture (repro.core.gcn.GCNConfig). None-valued fields
     are inferred from the materialized graph's features/labels."""
-    hidden_dim: int = _f(512, "hidden width of every inner layer")
+    arch: str = _f("gcn", "layer equation: 'gcn' (the paper's "
+                   "A'(XW + b)) or 'gat' (graph attention, "
+                   "repro.core.gat: masked softmax over the dense batch "
+                   "A's non-zeros; dense batches only)")
+    heads: Optional[List[int]] = _f(None, "gat only: attention heads of "
+                                    "each layer (one count per layer); "
+                                    "hidden layers concatenate theirs, "
+                                    "the output layer averages")
+    hidden_dim: int = _f(512, "hidden width of every inner layer (gat: "
+                         "the width of each head)")
     num_layers: int = _f(3, "number of GCN layers")
     dropout: float = _f(0.2, "feature dropout rate (paper §4: 20%)")
     residual: bool = _f(False, "add the paper Eq. 8 residual "
@@ -517,6 +528,28 @@ def validate(spec: ExperimentSpec) -> ExperimentSpec:
           f"got {spec.model.loss_scaling!r}")
     check(spec.model.loss_scale > 0, "model.loss_scale", "> 0")
     check(spec.model.remat_chunk >= 1, "model.remat_chunk", ">= 1")
+    m = spec.model
+    check(m.arch in _ARCHS, "model.arch",
+          f"must be one of {_ARCHS}; got {m.arch!r}")
+    if m.arch == "gat":
+        check(m.heads is not None and len(m.heads) == m.num_layers
+              and all(isinstance(k, int) and k >= 1 for k in m.heads),
+              "model.heads", f"gat needs one head count >= 1 per layer "
+              f"({m.num_layers}); got {m.heads!r}")
+        # the attention coefficients are computed on the device from the
+        # parameters; these paths carry a host-built A instead
+        for field, on in (("batch.sparse_adj", spec.batch.sparse_adj),
+                          ("model.fuse_spmm", m.fuse_spmm),
+                          ("model.precompute_ax", m.precompute_ax)):
+            check(not on, field, "gat runs on the dense batch A only, "
+                  "with per-edge coefficients it computes itself; set "
+                  f"{field}=false")
+        check(not m.layernorm, "model.layernorm",
+              "gat has no layer norm (ELU between layers); set "
+              "model.layernorm=false")
+    else:
+        check(m.heads is None, "model.heads",
+              f"applies to arch='gat' only; got {m.heads!r}")
     check(spec.execution.microbatches >= 1, "execution.microbatches",
           ">= 1")
     gs = spec.execution.compression_group_size
@@ -634,7 +667,8 @@ def build_gcn_config(spec: ExperimentSpec, graph: CSRGraph) -> GCNConfig:
         precompute_ax=m.precompute_ax, precision=m.precision,
         loss_scaling=m.loss_scaling, loss_scale=m.loss_scale,
         remat=m.remat, remat_chunk=m.remat_chunk,
-        fuse_spmm=m.fuse_spmm)
+        fuse_spmm=m.fuse_spmm, arch=m.arch,
+        heads=None if m.heads is None else tuple(m.heads))
 
 
 def build_optimizer(spec: ExperimentSpec) -> Optimizer:
@@ -776,6 +810,7 @@ _PRESETS: Dict[str, Union[str, Callable[[], ExperimentSpec]]] = {
     # "module:function", resolved lazily (keeps configs ↔ core acyclic)
     "ppi": "repro.configs.ppi:spec",
     "ppi_sota": "repro.configs.ppi:sota_spec",
+    "ppi_gat": "repro.configs.ppi:gat_spec",
     "ppi_tiny": "repro.configs.ppi:tiny_spec",
     "ppi_tiny_saint": "repro.configs.ppi:tiny_saint_spec",
     "ppi_deep_tiny": "repro.configs.ppi:deep_tiny_spec",

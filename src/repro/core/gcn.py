@@ -8,16 +8,20 @@ dispatches through the adjacency-polymorphic `spmm` (repro.kernels.ops):
 a dense Â keeps the XLA matmul; a BlockEllAdj batch (ClusterBatcher
 sparse_adj=True) routes to the differentiable block-ELL Pallas product
 whose backward runs on the host-built transposed tiles.
+
+With `arch="gat"` the same layer loop runs graph attention layers
+(repro.core.gat) on the dense batch Â, whose non-zeros are the mask.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import gat
 from repro.core.precision import policy_from_config
 from repro.kernels.ops import spmm as spmm_dispatch
 from repro.kernels.ops import spmm_xw as spmm_xw_dispatch
@@ -49,6 +53,9 @@ class GCNConfig:
                                   # the fused one-pass kernel seam
                                   # (ops.spmm_xw) instead of matmul-then-
                                   # spmm; same math, no XW HBM round-trip
+    arch: str = "gcn"             # "gcn" | "gat" (repro.core.gat)
+    heads: Optional[Tuple[int, ...]] = None  # gat: heads of each layer;
+                                  # hidden_dim is then the width per head
 
     @property
     def dims(self):
@@ -58,6 +65,8 @@ class GCNConfig:
 
 
 def init_gcn(key, cfg: GCNConfig) -> PyTree:
+    if cfg.arch == "gat":
+        return gat.init_gat(key, cfg)
     params = {"layers": []}
     for i, (din, dout) in enumerate(cfg.dims):
         key, k1 = jax.random.split(key)
@@ -93,6 +102,11 @@ def gcn_forward(params: PyTree, adj, x: jnp.ndarray,
     of `remat_chunk` and each chunk is wrapped in jax.checkpoint, so the
     backward pass holds one chunk boundary per chunk instead of every
     layer's activations — the knob that lets 8-10-layer GCNs fit.
+
+    Architecture (cfg.arch): "gat" runs `gat.gat_layer` in place of
+    Â(XW + b), masked by the dense Â's non-zeros and its diagonal, with
+    the identity skip and ELU between layers and the heads averaged at
+    the output; dropout, remat and the precision policy are shared.
     """
     pol = policy_from_config(cfg)
     cd = pol.compute_dtype
@@ -111,6 +125,10 @@ def gcn_forward(params: PyTree, adj, x: jnp.ndarray,
         else:
             keys.append(None)
 
+    if cfg.arch == "gat":
+        with jax.named_scope("gat.attention"):
+            mask = gat.attention_mask(adj)
+
     # named scopes (repro.runtime.tracing) label the device ops of each
     # part of a layer in a profiler trace, backward ops included
     def layer_fn(i, h, layer, key):
@@ -118,6 +136,13 @@ def gcn_forward(params: PyTree, adj, x: jnp.ndarray,
             with jax.named_scope("gcn.dropout"):
                 keep = 1.0 - cfg.dropout
                 h = h * jax.random.bernoulli(key, keep, h.shape) / keep
+        if cfg.arch == "gat":
+            z = gat.gat_layer(layer, h, mask, concat=i < n - 1,
+                              compute_dtype=cd)
+            if i < n - 1:
+                with jax.named_scope("gcn.activation"):
+                    z = gat.activate(z, h, cfg.residual)
+            return z.astype(cd)
         propagate = not (i == 0 and cfg.precompute_ax)
         if cfg.fuse_spmm and propagate:
             # fused Â·(XW + b): one seam, no XW materialization between

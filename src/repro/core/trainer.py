@@ -23,6 +23,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro.core import gat
 from repro.core.batching import ClusterBatcher
 from repro.core.engine import (Engine, EvalHook, LoggingHook,  # noqa: F401
                                ShardMapBackend, SingleDeviceBackend,
@@ -37,7 +38,10 @@ from repro.nn.optim import Optimizer
 def full_graph_logits(params, graph: CSRGraph, cfg: GCNConfig,
                       norm: str = "eq10", diag_lambda: float = 0.0,
                       batch_rows: int = 65536) -> np.ndarray:
-    """Exact layer-wise propagation on the host (scipy CSR)."""
+    """Exact layer-wise propagation on the host (scipy CSR); graph
+    attention (cfg.arch="gat") propagates over the edge list instead."""
+    if cfg.arch == "gat":
+        return gat.full_graph_logits(params, graph, cfg.residual)
     import scipy.sparse as sp
     ip, ix, dt = normalize_csr(graph.indptr, graph.indices, graph.data,
                                norm, diag_lambda)

@@ -93,6 +93,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     spec = load_spec(args)
+    if spec.model.arch != "gcn":
+        ap.error(f"model.arch={spec.model.arch!r}: serving runs the GCN "
+                 f"propagation only; graph attention is trained, not "
+                 f"served")
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     ckpt_dir = (args.checkpoint_dir or spec.run.checkpoint_dir

@@ -43,6 +43,9 @@ with whatever XLA fused into them:
 | `gcn.dropout`, `gcn.activation`, `gcn.loss` | the rest of the model | docs/tracing.md (the scopes each kernel holds) |
 | `optim.update` | optimizer update and apply (`core/engine.py`, `dist/steps.py`) | docs/tracing.md (the scopes each kernel holds) |
 | `dp.allreduce` | the data-parallel gradient all-reduce (`dist/steps.py`) | docs/tracing.md (four-chip step) |
+| `gat.xw` | graph attention's W·h and its s, t projections (`core/gat.py`) | — |
+| `gat.attention` | graph attention's mask, logits, LeakyReLU and softmax | `gat_attention_ms`, `gat_attention_roofline_pct` |
+| `gat.aggregate` | graph attention's α·(W·h), bias and head concat or mean | `gat_aggregate_ms`, `gat_attention_roofline_pct` |
 
 JAX's persistent compilation cache leaves op metadata out of its key
 unless told otherwise (`repro.launch.compile_cache`): a cached

@@ -71,6 +71,9 @@ class ServeEngine:
                  max_batch: int = 256, top_k: int = 5, block: int = 128,
                  imbalance_threshold: float = 2.0,
                  on_rebalance: Optional[Callable] = None):
+        if cfg.arch != "gcn":
+            raise ValueError(f"cfg.arch={cfg.arch!r}: the embedding cache "
+                             f"holds GCN propagations only")
         self.params = params
         self.graph = graph
         self.parts = np.asarray(parts)
